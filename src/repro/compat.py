@@ -1,69 +1,18 @@
-"""JAX version-skew shims.
+"""The device mesh constructor the repo uses, in one place.
 
-The repo targets the post-0.5 JAX surface (``jax.shard_map``,
-``jax.lax.pcast``, explicit mesh axis types); CI and the baked container pin
-older releases where those names live elsewhere or don't exist.  Everything
-version-sensitive routes through here so call sites stay on the modern
-spelling:
-
-  * ``make_mesh(shape, names)``: passes ``axis_types=(Auto, ...)`` when the
-    running JAX understands it, plain ``jax.make_mesh`` otherwise.
-  * ``shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)``:
-    ``jax.shard_map`` when present, else the ``jax.experimental`` one with
-    ``check_vma`` mapped onto ``check_rep``.  Replication checking is
-    disabled on the fallback — old-JAX rep inference predates ``pcast`` and
-    rejects the varying-accumulator patterns in ``core/distributed.py``.
-  * ``pcast(x, axes, to=...)``: identity where ``jax.lax.pcast`` doesn't
-    exist (it only annotates varying-ness for the new check machinery).
+``make_mesh(shape, names)`` is ``jax.make_mesh`` with every axis
+``AxisType.Auto``: the sharding rules here are written for Auto axes.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh", "set_mesh", "shard_map", "pcast"]
+__all__ = ["make_mesh"]
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types when supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            axis_shapes, axis_names, axis_types=(axis_type.Auto,) * len(axis_names)
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-else:
-
-    def set_mesh(mesh):
-        """Ambient-mesh context: old JAX meshes are context managers."""
-        return mesh
-
-
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        del check_vma  # rep checking predates pcast; always off on old JAX
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
-
-
-if hasattr(jax.lax, "pcast"):
-    pcast = jax.lax.pcast
-else:
-
-    def pcast(x, axes, *, to):
-        del axes, to
-        return x
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -23,10 +23,10 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import pcast, shard_map
 
 from .pairwise import pack_sketch
 from .sketch import LpSketch, SketchConfig, sketch, sketch_moments

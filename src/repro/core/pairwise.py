@@ -25,6 +25,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.precision import MATMUL_PRECISION
+
 from .decomposition import interaction_orders
 from .estimators import margin_mle_root
 from .sketch import LpSketch, SketchConfig
@@ -71,7 +73,7 @@ def pairwise_distances(
     sb = sa if self_pairs else sb
     A, _, na = pack_sketch(sa, cfg)
     _, B, nb = pack_sketch(sb, cfg)
-    D = na[:, None] + nb[None, :] + A @ B.T
+    D = na[:, None] + nb[None, :] + jnp.matmul(A, B.T, precision=MATMUL_PRECISION)
     if clip:
         D = jnp.maximum(D, 0.0)
     if zero_diag and self_pairs:
@@ -103,7 +105,7 @@ def pairwise_margin_mle(
             U, V = sa.U[:, a - 1], sb_.U[:, c - 1]
         else:
             U, V = sa.U[:, m - 1], sb_.U[:, no + m - 1]
-        t = U @ V.T
+        t = jnp.matmul(U, V.T, precision=MATMUL_PRECISION)
         nu = jnp.sum(U * U, axis=-1)[:, None]
         nv = jnp.sum(V * V, axis=-1)[None, :]
         Mx = sa.moments[:, a - 1][:, None]

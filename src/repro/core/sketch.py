@@ -32,6 +32,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.precision import MATMUL_PRECISION
+
 from .decomposition import interaction_orders, power_moments
 from .projections import (
     ProjectionSpec,
@@ -179,25 +181,26 @@ def sketch_block_contrib(
             idx, vals = projection_sparse_block(
                 mkey, block_index, xb.shape[-1], k, cfg.projection)
             # (n, m, k) gather then contract m: the sparse ingest fast path
-            u = jnp.einsum("nmk,mk->nk", xf[:, idx], vals)
+            u = jnp.einsum("nmk,mk->nk", xf[:, idx], vals,
+                           precision=MATMUL_PRECISION)
         else:
             R = projection_block(mkey, block_index, xb.shape[-1], k,
                                  cfg.projection)
-            u = xf @ R
+            u = jnp.matmul(xf, R, precision=MATMUL_PRECISION)
         return u[:, None, :]
     pw = _powers(xb.astype(cfg.projection.dtype), p)  # (n, p-1, bd)
     if cfg.strategy == "basic":
         R = projection_block(_matrix_key(key, _BASIC_MATRIX_ID), block_index,
                              xb.shape[-1], k, cfg.projection)
-        return jnp.einsum("njd,dk->njk", pw, R)
+        return jnp.einsum("njd,dk->njk", pw, R, precision=MATMUL_PRECISION)
     # alternative: term m uses R^(m) for both roles
     ua, ub = [], []
     for a, c, _ in interaction_orders(p):  # a = p-m, c = m
         m = c
         R = projection_block(_matrix_key(key, m), block_index,
                              xb.shape[-1], k, cfg.projection)
-        ua.append(pw[:, a - 1] @ R)
-        ub.append(pw[:, c - 1] @ R)
+        ua.append(jnp.matmul(pw[:, a - 1], R, precision=MATMUL_PRECISION))
+        ub.append(jnp.matmul(pw[:, c - 1], R, precision=MATMUL_PRECISION))
     return jnp.stack(ua + ub, axis=1)
 
 

@@ -6,12 +6,14 @@ the (n, m) matrix.  Defaults are tuned per platform:
 
   * tpu: the Pallas ``pairwise_lp`` kernel with MXU-friendly 1024x1024 strips
     (the kernel tiles further into bm x bn x bk internally).
-  * gpu: pure-XLA strips, large blocks (cuBLAS does its own tiling).
   * cpu: pure-XLA strips, 512x512 — small enough that tests exercise multiple
     strips, big enough that Eigen GEMMs stay efficient.
 
+Any other platform is an error, never a silent CPU default: a device the
+table does not know has no tuned strips and no tested kernel.
+
 ``backend="interpret"`` forces the Pallas kernel through the interpreter —
-slow, but it executes the exact kernel program on CPU (used by tests/CI).
+slow, but it executes the exact kernel program on CPU (a test-only backend).
 """
 
 from __future__ import annotations
@@ -28,14 +30,22 @@ BACKENDS = ("auto", "pallas", "interpret", "xla")
 # platform -> (backend, row_block, col_block)
 _PLATFORM_DEFAULTS = {
     "tpu": ("pallas", 1024, 1024),
-    "gpu": ("xla", 2048, 2048),
     "cpu": ("xla", 512, 512),
 }
 
 
-def default_backend(platform: Optional[str] = None) -> str:
+def _platform_defaults(platform: Optional[str]) -> Tuple[str, int, int]:
     platform = platform or jax.default_backend()
-    return _PLATFORM_DEFAULTS.get(platform, _PLATFORM_DEFAULTS["cpu"])[0]
+    try:
+        return _PLATFORM_DEFAULTS[platform]
+    except KeyError:
+        raise ValueError(
+            f"no engine defaults for platform {platform!r} (known: "
+            f"{sorted(_PLATFORM_DEFAULTS)})") from None
+
+
+def default_backend(platform: Optional[str] = None) -> str:
+    return _platform_defaults(platform)[0]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,7 +54,8 @@ class EngineConfig:
 
     Attributes:
       backend: "auto" (resolve by platform), "pallas" (TPU kernel),
-        "interpret" (Pallas interpreter on CPU), or "xla" (pure jnp strips).
+        "interpret" (Pallas interpreter on CPU, for tests), or "xla" (pure
+        jnp strips).
       row_block: strip height over the left/query rows.
       col_block: strip width over the right/corpus rows.
     """
@@ -63,9 +74,6 @@ class EngineConfig:
 
     def resolve(self, platform: Optional[str] = None) -> Tuple[str, int, int]:
         """(backend, row_block, col_block) with platform defaults filled in."""
-        platform = platform or jax.default_backend()
-        dflt_backend, dflt_rb, dflt_cb = _PLATFORM_DEFAULTS.get(
-            platform, _PLATFORM_DEFAULTS["cpu"]
-        )
+        dflt_backend, dflt_rb, dflt_cb = _platform_defaults(platform)
         backend = dflt_backend if self.backend == "auto" else self.backend
         return backend, self.row_block or dflt_rb, self.col_block or dflt_cb

@@ -123,6 +123,10 @@ _STAGE1_PARALLEL = REGISTRY.counter(
     "index.stage1_parallel", "stage-1 fans served by the stacked shard_map")
 _STAGE1_DISPATCH = REGISTRY.counter(
     "index.stage1_dispatch", "stage-1 fans served by the dispatch fallback")
+_FAN_MESH_DECLINED = REGISTRY.counter(
+    "index.stacked_fan_declined",
+    "sharded indexes built without a stacked fan because the mesh and the "
+    "shard device list disagree")
 _STACK_HITS = REGISTRY.counter(
     "index.stack_cache_hits", "stacked-operand cache hits")
 _STACK_MISSES = REGISTRY.counter(
@@ -446,14 +450,25 @@ class ShardedSketchIndex(SketchIndex):
         self.mesh = mesh
         # the stacked fan needs shard i of the stack and segment placement to
         # agree on a physical device; a mesh that disagrees with the explicit
-        # device list (or duplicate fake shards) falls back to dispatch mode
+        # device list (or duplicate fake shards) leaves only the dispatch fan,
+        # and says why in stats()["stacked_fan_declined"] and a counter
         self._fan_mesh = None
-        if mesh is not None:
+        self._fan_declined: Optional[str] = None
+        if mesh is None:
+            self._fan_declined = "no mesh: duplicate shard devices"
+        else:
             try:
-                if list(mesh_shard_devices(mesh, data_axes)) == self.devices:
+                mesh_devs = list(mesh_shard_devices(mesh, data_axes))
+            except (KeyError, ValueError) as e:
+                self._fan_declined = f"mesh has no data axes {data_axes!r}: {e}"
+            else:
+                if mesh_devs == self.devices:
                     self._fan_mesh = mesh
-            except (KeyError, ValueError):
-                pass
+                else:
+                    self._fan_declined = (
+                        "mesh data-axis devices differ from the shard devices")
+        if self._fan_declined is not None:
+            _FAN_MESH_DECLINED.inc()
         self._stack: Optional[_StackedOperands] = None
         self._last_stage1: Optional[str] = None  # mode of the last query
         # last OBSERVED stage-1 mode per estimator — what stats() reports
@@ -492,6 +507,7 @@ class ShardedSketchIndex(SketchIndex):
             for est in registry.names_for(self.cfg)
         }
         s["stage1"]["last"] = self._last_stage1
+        s["stacked_fan_declined"] = self._fan_declined
         s["planner"] = self.planner.stats()
         s["auto_rebalances"] = self.auto_rebalances
         return s

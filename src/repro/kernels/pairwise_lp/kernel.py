@@ -19,6 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.precision import MATMUL_PRECISION
 
 __all__ = ["pairwise_lp_kernel", "pairwise_lp_call"]
 
@@ -33,12 +36,14 @@ def pairwise_lp_kernel(a_ref, b_ref, na_ref, nb_ref, d_ref, *, nsteps: int, clip
     a = a_ref[...].astype(jnp.float32)  # (bm, bk)
     b = b_ref[...].astype(jnp.float32)  # (bn, bk)
     d_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (1,)), ((), ())),
+        precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(kstep == nsteps - 1)
     def _epilogue():
-        d = d_ref[...] + na_ref[...][:, None] + nb_ref[...][None, :]
+        d = d_ref[...] + na_ref[...] + nb_ref[...]  # (bm, 1) + (1, bn)
         if clip:
             d = jnp.maximum(d, 0.0)
         d_ref[...] = d
@@ -75,20 +80,23 @@ def pairwise_lp_call(
     npp, Kp = A.shape
     mpp = B.shape[0]
     grid = (npp // bm, mpp // bn, Kp // bk)
+    # the norms ride as 2-D (bm, 1) / (1, bn) blocks: Mosaic refuses 1-D
+    # blocks whose XLA layout tiling differs from its own
+    na = na.astype(jnp.float32).reshape(npp, 1)
+    nb = nb.astype(jnp.float32).reshape(1, mpp)
     out = pl.pallas_call(
         functools.partial(pairwise_lp_kernel, nsteps=grid[2], clip=clip),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, s: (i, s)),
             pl.BlockSpec((bn, bk), lambda i, j, s: (j, s)),
-            pl.BlockSpec((bm,), lambda i, j, s: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, s: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, s: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, s: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((npp, mpp), jnp.float32),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(A, B, na, nb)
     return out[:n, :m]

@@ -11,13 +11,9 @@ from .kernel import pairwise_lp_call
 from .ref import pairwise_lp_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def pairwise_lp(A, B, na, nb, *, clip=True, use_kernel=True, interpret=None):
-    if interpret is None:
-        interpret = not _on_tpu()
+def pairwise_lp(A, B, na, nb, *, clip=True, use_kernel=True, interpret=False):
+    """The kernel (``interpret=True`` runs it through the Pallas interpreter,
+    a test-only mode that is never chosen by platform)."""
     if not use_kernel:
         return pairwise_lp_ref(A, B, na, nb, clip=clip)
     return pairwise_lp_call(A, B, na, nb, clip=clip, interpret=interpret)
@@ -29,7 +25,7 @@ def pairwise_distances_kernel(
     cfg: SketchConfig,
     *,
     clip: bool = True,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Drop-in kernel-backed version of repro.core.pairwise_distances."""
     sb = sa if sb is None else sb

@@ -26,6 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.precision import MATMUL_PRECISION
 
 __all__ = ["power_project_kernel", "power_project_call"]
 
@@ -45,7 +48,8 @@ def power_project_kernel(x_ref, r_ref, u_ref, *, powers: tuple[int, ...]):
     partials = {}
     for j in range(1, max_pow + 1):
         if j in powers:
-            partials[j] = jnp.dot(xp, r, preferred_element_type=jnp.float32)
+            partials[j] = jnp.dot(xp, r, precision=MATMUL_PRECISION,
+                                  preferred_element_type=jnp.float32)
         if j < max_pow:
             xp = xp * x
     for slot, j in enumerate(powers):
@@ -92,8 +96,7 @@ def power_project_call(
         out_specs=pl.BlockSpec((bm, len(powers), k), lambda i, d: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((npads, len(powers), k), jnp.float32),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(X, R)
     return out[:n]

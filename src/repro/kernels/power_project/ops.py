@@ -1,8 +1,9 @@
 """Jitted public wrapper for the fused power+projection kernel.
 
-Chooses the Pallas kernel on TPU, interpret-mode Pallas when asked (tests),
-and integrates with the sketching API: ``sketch_via_kernel`` produces the
-same ``LpSketch`` as ``repro.core.sketch`` (same streamed R tiles)."""
+Runs the Pallas kernel, or the Pallas interpreter when a test asks for it
+(``interpret=True``; never chosen by platform), and integrates with the
+sketching API: ``sketch_via_kernel`` produces the same ``LpSketch`` as
+``repro.core.sketch`` (same streamed R tiles)."""
 
 from __future__ import annotations
 
@@ -17,23 +18,15 @@ from .kernel import power_project_call
 from .ref import power_project_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def power_project(X, R, powers, *, use_kernel: bool | None = None, interpret: bool | None = None):
+def power_project(X, R, powers, *, use_kernel: bool = True, interpret: bool = False):
     """Dispatch between the Pallas kernel and the jnp reference."""
-    if use_kernel is None:
-        use_kernel = True
-    if interpret is None:
-        interpret = not _on_tpu()
     if not use_kernel:
         return power_project_ref(X, R, tuple(powers))
     return power_project_call(X, R, tuple(powers), interpret=interpret)
 
 
 def sketch_via_kernel(
-    X: jax.Array, key: jax.Array, cfg: SketchConfig, *, interpret: bool | None = None
+    X: jax.Array, key: jax.Array, cfg: SketchConfig, *, interpret: bool = False
 ) -> LpSketch:
     """LpSketch built by the fused kernel — same R stream as repro.core.sketch."""
     n, D = X.shape

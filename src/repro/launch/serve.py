@@ -18,6 +18,7 @@ from repro.compat import make_mesh
 from repro.core import registry
 from repro.configs.base import TrainKnobs, reduced
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_parallel
 from repro.models import build_model
 from repro.runtime.serve import SketchKnnService, generate
@@ -68,6 +69,7 @@ def main(argv=None):
                          "(bit-identical answers; queries route to the "
                          "least-loaded lane)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace:
         obs.enable()

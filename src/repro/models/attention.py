@@ -15,9 +15,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.parallel.sharding import Parallel
 

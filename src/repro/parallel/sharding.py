@@ -18,10 +18,9 @@ from typing import Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 __all__ = ["ShardingRules", "Parallel", "logical_to_spec", "shard_act"]
 

@@ -162,3 +162,13 @@ def test_engine_validates_arguments():
         EngineConfig(backend="cuda")
     with pytest.raises(ValueError):
         EngineConfig(row_block=0)
+
+
+def test_platform_defaults_never_fall_back_to_cpu():
+    assert EngineConfig().resolve("tpu") == ("pallas", 1024, 1024)
+    assert EngineConfig(col_block=64).resolve("cpu") == ("xla", 512, 64)
+    for platform in ("gpu", "rocm", "metal"):
+        with pytest.raises(ValueError, match="no engine defaults"):
+            EngineConfig().resolve(platform)
+        with pytest.raises(ValueError, match="no engine defaults"):
+            engine.default_backend(platform)

@@ -20,7 +20,7 @@ _CHILD = textwrap.dedent(
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.parallel.sharding import Parallel, ShardingRules, tp_out_project
     from repro.models.embed_sharded import sharded_ce_loss, sharded_embed_lookup
-    from repro.compat import make_mesh, set_mesh
+    from repro.compat import make_mesh
 
     mesh = make_mesh((2, 4), ("data", "model"))
     par = Parallel(mesh=mesh, rules=ShardingRules.default(sequence_parallel=True),
@@ -31,7 +31,7 @@ _CHILD = textwrap.dedent(
     # ---- embedding lookup fwd + grad
     emb = jax.random.normal(key, (V, E))
     toks = jax.random.randint(jax.random.key(1), (B, S), 0, V)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda e: sharded_embed_lookup(par, e, toks))(emb)
     want = jnp.take(emb, toks, axis=0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
@@ -40,7 +40,7 @@ _CHILD = textwrap.dedent(
         return jnp.sum(sharded_embed_lookup(par, e, toks) ** 2)
     def esum_ref(e):
         return jnp.sum(jnp.take(e, toks, axis=0) ** 2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g1 = jax.jit(jax.grad(esum))(emb)
     g2 = jax.grad(esum_ref)(emb)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-4, atol=1e-4)
@@ -58,10 +58,10 @@ _CHILD = textwrap.dedent(
         ll = jnp.take_along_axis(logits, jnp.maximum(lb, 0)[..., None], -1)[..., 0]
         return jnp.sum((lse - ll) * (lb >= 0))
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss = jax.jit(lambda h, w_: sharded_ce_loss(par, h, w_, lb))(hid, w)
     np.testing.assert_allclose(float(loss), float(ce_ref(hid, w)), rtol=1e-5)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         gh, gw = jax.jit(jax.grad(
             lambda h, w_: sharded_ce_loss(par, h, w_, lb), argnums=(0, 1)))(hid, w)
     gh_r, gw_r = jax.grad(ce_ref, argnums=(0, 1))(hid, w)
@@ -78,11 +78,11 @@ _CHILD = textwrap.dedent(
     def proj_ref(h_, w_):
         return jnp.sum((h_ @ w_) ** 2)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda h_, w_: tp_out_project(par, h_, w_))(h, wd)
     np.testing.assert_allclose(np.asarray(out), np.asarray(h @ wd),
                                rtol=1e-4, atol=1e-4)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         gh, gw = jax.jit(jax.grad(proj, argnums=(0, 1)))(h, wd)
     gh_r, gw_r = jax.grad(proj_ref, argnums=(0, 1))(h, wd)
     np.testing.assert_allclose(np.asarray(gh), np.asarray(gh_r), rtol=1e-4, atol=1e-4)

@@ -448,3 +448,20 @@ def test_stage1_stats_per_estimator_and_last_mode(rng):
     sh2.query(Q, top_k=5)
     s2 = sh2.stats()["stage1"]
     assert s2 == {"plain": "dispatch", "mle": "dispatch", "last": "dispatch"}
+
+
+def test_declined_stacked_fan_is_counted_and_visible(rng):
+    """A stacked fan that cannot be built is never silent: stats() names
+    the reason and the process-wide counter moves."""
+    from repro.obs.metrics import REGISTRY
+
+    ok = ShardedSketchIndex(CFG, seed=1, mesh=make_serving_mesh(1))
+    assert ok.stats()["stacked_fan_declined"] is None
+    counter = REGISTRY.counter("index.stacked_fan_declined")
+    before = counter.value
+    dup = ShardedSketchIndex(CFG, seed=1, devices=[ok.devices[0]] * 2)
+    assert "duplicate" in dup.stats()["stacked_fan_declined"]
+    other = ShardedSketchIndex(CFG, seed=1, mesh=make_serving_mesh(1),
+                               devices=ok.devices, data_axes="model")
+    assert "no data axes" in other.stats()["stacked_fan_declined"]
+    assert counter.value == before + 2
