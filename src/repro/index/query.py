@@ -147,14 +147,11 @@ def _fold_segment_topk(vals, idx, qsk, q_packed, seg: Segment,
     n = _segment_rows(seg)
     strip = _segment_strip_fn(qsk, q_packed, seg, cfg, spec, backend)
     c = min(k, n)
-    # spans here time the host-side strip loop: jax dispatch is async, so
-    # device compute lands in whichever span later blocks on the result
-    with obs.span("engine.strips", rows=n, base=base):
-        for c0, c1 in strip_bounds(n, col_block):
-            D = strip(c0, c1)
-            neg, j = jax.lax.top_k(-D, min(c, c1 - c0))
-            cand_idx = (j + (base + c0)).astype(jnp.int32)
-            vals, idx = merge_topk(vals, idx, -neg, cand_idx, k)
+    for c0, c1 in strip_bounds(n, col_block):
+        D = strip(c0, c1)
+        neg, j = jax.lax.top_k(-D, min(c, c1 - c0))
+        cand_idx = (j + (base + c0)).astype(jnp.int32)
+        vals, idx = merge_topk(vals, idx, -neg, cand_idx, k)
     return vals, idx
 
 
@@ -223,24 +220,33 @@ def fan_topk(
     # order == ingest order, which is the dense corpus's tie-break order
     total = sum(_segment_rows(s) for s in segments)
     k_run = min(top_k, total)
-    vals = jnp.full((q, k_run), jnp.inf, jnp.float32)
-    idx = jnp.full((q, k_run), _IDX_SENTINEL, jnp.int32)
     base = 0
     id_map: List[np.ndarray] = []
-    q_packed = _pack_query(qsk, cfg, spec)
     with obs.span("index.fan.stage1", metric="index.stage1_dense_ms",
-                  mode="single", segments=len(segments)):
-        for seg in segments:
-            n = _segment_rows(seg)
-            vals, idx = _fold_segment_topk(vals, idx, qsk, q_packed, seg, cfg,
-                                           spec, backend, col_block,
-                                           base, k_run)
-            id_map.append(seg.row_ids[:n])
-            base += n
-
-        pos_to_id = np.concatenate(id_map) if id_map else np.zeros(0, np.int64)
-        k_out = _finite_k(np.asarray(vals), k_out)
-        pos = np.asarray(idx[:, :k_out])
+                  mode="single", segments=len(segments)) as sp:
+        # jax dispatch is async: the dispatch span times the host's strip
+        # loop, and the device work it queued is waited for under collect
+        with obs.span("index.fan.dispatch"):
+            vals = jnp.full((q, k_run), jnp.inf, jnp.float32)
+            idx = jnp.full((q, k_run), _IDX_SENTINEL, jnp.int32)
+            q_packed = _pack_query(qsk, cfg, spec)
+            strips = 0
+            for seg in segments:
+                n = _segment_rows(seg)
+                vals, idx = _fold_segment_topk(vals, idx, qsk, q_packed, seg,
+                                               cfg, spec, backend, col_block,
+                                               base, k_run)
+                id_map.append(seg.row_ids[:n])
+                base += n
+                if sp:
+                    strips += len(strip_bounds(n, col_block))
+        if sp:
+            sp.set(strips=strips)
+        with obs.span("index.fan.collect"):
+            pos_to_id = (np.concatenate(id_map) if id_map
+                         else np.zeros(0, np.int64))
+            k_out = _finite_k(np.asarray(vals), k_out)
+            pos = np.asarray(idx[:, :k_out])
     return vals[:, :k_out], pos_to_id[pos]
 
 
@@ -389,6 +395,7 @@ class MicroBatcher:
             self.results = None
             self.error: Optional[BaseException] = None
             self.t_open = obs.trace.clock()  # for the queue-wait histogram
+            self.joined: List[float] = []  # each request's join time
             self.deadline: Optional[float] = None  # tightest absolute deadline
 
     def query(self, rows, top_k: int = 10,
@@ -411,8 +418,8 @@ class MicroBatcher:
             k_out = min(top_k, self.index.n_live)
             return (jnp.zeros((0, k_out), jnp.float32),
                     np.zeros((0, k_out), np.int64))
-        deadline_abs = (None if deadline_ms is None
-                        else obs.trace.clock() + deadline_ms / 1e3)
+        now = obs.trace.clock()
+        deadline_abs = None if deadline_ms is None else now + deadline_ms / 1e3
         key = (top_k, estimator, approx_ok)
         with self._lock:
             batch = self._groups.get(key)
@@ -421,6 +428,7 @@ class MicroBatcher:
             my = batch
             lo = my.n
             my.rows.append(rows)
+            my.joined.append(now)
             my.n += rows.shape[0]
             if deadline_abs is not None and (my.deadline is None
                                              or deadline_abs < my.deadline):
@@ -470,7 +478,11 @@ class MicroBatcher:
             # engine ran once, so that is the honest accounting; the index's
             # own index.query span nests under this root
             with obs.span("batcher.query", metric="batcher.flush_ms",
-                          rows=n, top_k=top_k, estimator=estimator):
+                          rows=n, top_k=top_k, estimator=estimator) as sp:
+                if sp:
+                    # mean over the batch's requests of flush start - join
+                    wait = sp.t0 - sum(batch.joined) / len(batch.joined)
+                    sp.set(requests=len(batch.joined), queue_wait_ms=1e3 * wait)
                 batch.results = self.index.query(X, top_k=top_k,
                                                  estimator=estimator,
                                                  approx_ok=approx_ok)

@@ -506,27 +506,43 @@ class SketchIndex:
         serving front door) is advisory plan context — the planner may pick
         a cheaper measured route for it, but the index never drops work.
         """
-        qsk = sketch(jnp.asarray(rows), self.key, self.cfg)
-        return self.query_sketch(qsk, top_k=top_k, estimator=estimator,
-                                 approx_ok=approx_ok, deadline_ms=deadline_ms)
+        with obs.span("index.query", metric="index.query_ms", kind="topk",
+                      top_k=top_k, estimator=estimator) as sp:
+            qsk = self._sketch_query(rows, sp)
+            return self._topk(qsk, top_k, estimator, approx_ok, deadline_ms,
+                              sp)
 
     def query_sketch(self, qsk: LpSketch, top_k: int = 10,
                      estimator: str = registry.DEFAULT_ESTIMATOR, *,
                      approx_ok: Optional[ApproxContract] = None,
                      deadline_ms: Optional[float] = None):
+        """``query`` for rows already sketched with this index's key."""
         with obs.span("index.query", metric="index.query_ms", kind="topk",
-                      top_k=top_k, estimator=estimator, rows=qsk.n):
-            plan = self.planner.plan(reduce="topk", estimator=estimator,
-                                     sharded=False, approx_ok=approx_ok,
-                                     deadline_ms=deadline_ms,
-                                     replica=self.replica_id)
-            t0 = time.perf_counter()
-            out = fan_topk(qsk, self._segments(), self.cfg,
-                           top_k=top_k, estimator=estimator,
-                           engine=self.engine)
-            self.planner.observe(plan, "dense",
-                                 (time.perf_counter() - t0) * 1e3)
-            return out
+                      top_k=top_k, estimator=estimator, rows=qsk.n) as sp:
+            return self._topk(qsk, top_k, estimator, approx_ok, deadline_ms,
+                              sp)
+
+    def _sketch_query(self, rows, sp) -> LpSketch:
+        """The query rows' sketch, under the ``index.query`` span ``sp``."""
+        with obs.span("index.sketch"):
+            qsk = sketch(jnp.asarray(rows), self.key, self.cfg)
+        if sp:
+            sp.set(rows=qsk.n)
+        return qsk
+
+    def _topk(self, qsk: LpSketch, top_k: int, estimator: str, approx_ok,
+              deadline_ms, sp):
+        """Plan and serve one top-k query inside its ``index.query`` span
+        ``sp`` (the sharded index serves its routes here)."""
+        plan = self.planner.plan(reduce="topk", estimator=estimator,
+                                 sharded=False, approx_ok=approx_ok,
+                                 deadline_ms=deadline_ms,
+                                 replica=self.replica_id)
+        t0 = time.perf_counter()
+        out = fan_topk(qsk, self._segments(), self.cfg, top_k=top_k,
+                       estimator=estimator, engine=self.engine)
+        self.planner.observe(plan, "dense", (time.perf_counter() - t0) * 1e3)
+        return out
 
     def query_threshold(self, rows: jax.Array, radius: float, *,
                         relative: bool = False,
@@ -534,31 +550,39 @@ class SketchIndex:
                         approx_ok: Optional[ApproxContract] = None,
                         deadline_ms: Optional[float] = None):
         """(query_rows, row_ids) of live rows with D < radius."""
-        qsk = sketch(jnp.asarray(rows), self.key, self.cfg)
-        return self.query_threshold_sketch(qsk, radius=radius,
-                                           relative=relative,
-                                           estimator=estimator,
-                                           approx_ok=approx_ok,
-                                           deadline_ms=deadline_ms)
+        with obs.span("index.query", metric="index.threshold_ms",
+                      kind="threshold", estimator=estimator) as sp:
+            qsk = self._sketch_query(rows, sp)
+            return self._threshold(qsk, radius, relative, estimator,
+                                   approx_ok, deadline_ms, sp)
 
     def query_threshold_sketch(self, qsk: LpSketch, *, radius: float,
                                relative: bool = False,
                                estimator: str = registry.DEFAULT_ESTIMATOR,
                                approx_ok: Optional[ApproxContract] = None,
                                deadline_ms: Optional[float] = None):
+        """``query_threshold`` for rows already sketched with this index's
+        key."""
         with obs.span("index.query", metric="index.threshold_ms",
-                      kind="threshold", estimator=estimator, rows=qsk.n):
-            plan = self.planner.plan(reduce="threshold", estimator=estimator,
-                                     sharded=False, approx_ok=approx_ok,
-                                     deadline_ms=deadline_ms,
-                                     replica=self.replica_id)
-            t0 = time.perf_counter()
-            out = threshold_scan(qsk, self._segments(), self.cfg,
-                                 radius=radius, relative=relative,
-                                 estimator=estimator, engine=self.engine)
-            self.planner.observe(plan, "dense",
-                                 (time.perf_counter() - t0) * 1e3)
-            return out
+                      kind="threshold", estimator=estimator,
+                      rows=qsk.n) as sp:
+            return self._threshold(qsk, radius, relative, estimator,
+                                   approx_ok, deadline_ms, sp)
+
+    def _threshold(self, qsk: LpSketch, radius: float, relative: bool,
+                   estimator: str, approx_ok, deadline_ms, sp):
+        """Plan and serve one threshold query inside its ``index.query``
+        span ``sp``."""
+        plan = self.planner.plan(reduce="threshold", estimator=estimator,
+                                 sharded=False, approx_ok=approx_ok,
+                                 deadline_ms=deadline_ms,
+                                 replica=self.replica_id)
+        t0 = time.perf_counter()
+        out = threshold_scan(qsk, self._segments(), self.cfg, radius=radius,
+                             relative=relative, estimator=estimator,
+                             engine=self.engine)
+        self.planner.observe(plan, "dense", (time.perf_counter() - t0) * 1e3)
+        return out
 
     # ------------------------------------------------------------ persistence
 

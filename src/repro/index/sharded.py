@@ -746,26 +746,21 @@ class ShardedSketchIndex(SketchIndex):
         if sp:
             sp.set(stage1=label, planned=STAGE1_LABEL[plan.route])
 
-    def query_sketch(self, qsk: LpSketch, top_k: int = 10,
-                     estimator: str = registry.DEFAULT_ESTIMATOR, *,
-                     approx_ok: Optional[ApproxContract] = None,
-                     deadline_ms: Optional[float] = None):
+    def _topk(self, qsk: LpSketch, top_k: int, estimator: str, approx_ok,
+              deadline_ms, sp):
         registry.resolve(estimator, p=self.cfg.p,
                          projection=self.cfg.projection.family)
         _check_top_k(top_k)
-        with obs.span("index.query", metric="index.query_ms", kind="topk",
-                      top_k=top_k, estimator=estimator, rows=qsk.n) as sp:
-            segments = self._segments()
-            plan = self._plan("topk", estimator, approx_ok, deadline_ms)
-            for route in plan.chain:
-                t0 = time.perf_counter()
-                out = self._run_topk_route(route, plan, qsk, segments, top_k)
-                if out is not None:
-                    self._note_route(plan, route, time.perf_counter() - t0,
-                                     sp)
-                    return out
-            raise RuntimeError(  # dispatch is terminal: this cannot decline
-                f"no route served the query (plan: {plan.describe()})")
+        segments = self._segments()
+        plan = self._plan("topk", estimator, approx_ok, deadline_ms)
+        for route in plan.chain:
+            t0 = time.perf_counter()
+            out = self._run_topk_route(route, plan, qsk, segments, top_k)
+            if out is not None:
+                self._note_route(plan, route, time.perf_counter() - t0, sp)
+                return out
+        raise RuntimeError(  # dispatch is terminal: this cannot decline
+            f"no route served the query (plan: {plan.describe()})")
 
     def _run_topk_route(self, route: str, plan: QueryPlan, qsk: LpSketch,
                         segments, top_k: int):
@@ -1029,28 +1024,21 @@ class ShardedSketchIndex(SketchIndex):
                 return None  # fall back: dispatch recomputes (rare path)
         return out
 
-    def query_threshold_sketch(self, qsk: LpSketch, *, radius: float,
-                               relative: bool = False,
-                               estimator: str = registry.DEFAULT_ESTIMATOR,
-                               approx_ok: Optional[ApproxContract] = None,
-                               deadline_ms: Optional[float] = None):
+    def _threshold(self, qsk: LpSketch, radius: float, relative: bool,
+                   estimator: str, approx_ok, deadline_ms, sp):
         registry.resolve(estimator, p=self.cfg.p,
                          projection=self.cfg.projection.family)
-        with obs.span("index.query", metric="index.threshold_ms",
-                      kind="threshold", estimator=estimator,
-                      rows=qsk.n) as sp:
-            segments = self._segments()
-            plan = self._plan("threshold", estimator, approx_ok, deadline_ms)
-            for route in plan.chain:
-                t0 = time.perf_counter()
-                out = self._run_threshold_route(route, plan, qsk, segments,
-                                                radius, relative)
-                if out is not None:
-                    self._note_route(plan, route, time.perf_counter() - t0,
-                                     sp)
-                    return out
-            raise RuntimeError(
-                f"no route served the query (plan: {plan.describe()})")
+        segments = self._segments()
+        plan = self._plan("threshold", estimator, approx_ok, deadline_ms)
+        for route in plan.chain:
+            t0 = time.perf_counter()
+            out = self._run_threshold_route(route, plan, qsk, segments,
+                                            radius, relative)
+            if out is not None:
+                self._note_route(plan, route, time.perf_counter() - t0, sp)
+                return out
+        raise RuntimeError(
+            f"no route served the query (plan: {plan.describe()})")
 
     def _run_threshold_route(self, route: str, plan: QueryPlan,
                              qsk: LpSketch, segments, radius: float,

@@ -5,9 +5,11 @@ Three pieces, wired together:
   * :mod:`repro.obs.trace` — a monotonic-clock span tracer.  Stages wrap in
     ``with obs.span("index.fan.stage1", shards=4): ...``; spans nest into a
     per-thread tree, the root mints a process-unique trace id, and finished
-    roots flow to registered sinks.  **Disabled by default**: ``span()``
-    then returns one shared no-op object — the hot path pays a global load
-    and a branch, nothing else.
+    roots flow to registered sinks.  Each real span is also a profiler
+    annotation, so a ``jax.profiler`` capture shows it on the device
+    trace's clock.  **Disabled by default**: ``span()`` then returns
+    one shared no-op object — the hot path pays a global load and a branch,
+    nothing else.
   * :mod:`repro.obs.metrics` — a process-global registry of counters,
     gauges, and fixed-bucket latency histograms (p50/p95/p99 summaries,
     ``snapshot()`` dict, Prometheus text exposition, optional stdlib HTTP
@@ -46,15 +48,14 @@ trace.add_sink(GLOBAL_SLOW_LOG.offer)
 
 def enable(jax_scope: bool = False) -> None:
     """Turn tracing (and with it span-fed histograms + the slow-query log)
-    on.  ``jax_scope=True`` additionally annotates spans into
-    ``jax.named_scope`` for ``jax.profiler`` captures on TPU."""
+    on.  Spans always reach a running ``jax.profiler`` capture as
+    annotations; ``jax_scope`` is accepted and ignored (the benchmark
+    harness still passes it)."""
     trace.enable()
-    trace.set_jax_scope(jax_scope)
 
 
 def disable() -> None:
     trace.disable()
-    trace.set_jax_scope(False)
 
 
 def enabled() -> bool:

@@ -8,43 +8,55 @@ one branch per stage.  When **enabled**, spans form a per-thread tree: the
 first span opened on a thread becomes a trace root and mints a
 process-unique trace id; children attach to the innermost open span, and a
 finished root is handed to every registered sink (the slow-query log, test
-collectors).
+collectors) and kept in a bounded record of the roots finished since tracing
+was last enabled (``recent_roots``), for whoever reads a traced stretch
+afterwards.
 
 The micro-batcher's coalescing makes one flush serve many callers; the
 flusher's trace therefore carries the whole batch (its root span records the
 batch size), which is the honest accounting — the engine ran once.
 
-``set_jax_scope(True)`` additionally enters ``jax.named_scope(name)`` for
-every real span, so spans show up as annotations in ``jax.profiler`` traces
-on TPU; it is off by default because named_scope is only meaningful while a
-profiler trace is being captured.
+Every real span also enters ``jax.profiler.TraceAnnotation(name)`` for its
+lifetime, on its own thread, so a ``jax.profiler`` capture shows the span as
+a host event on the profiler's clock, beside the device's ops: an idle gap
+on the chip can then be named by the layer the host was in.  Outside a
+capture the annotation records nothing.
 """
 
 from __future__ import annotations
 
+import collections
 import itertools
 import threading
 import time
 from typing import Callable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
-    "span", "enable", "disable", "enabled", "set_jax_scope",
-    "current_trace_id", "add_sink", "remove_sink", "Span",
+    "span", "enable", "disable", "enabled",
+    "current_trace_id", "add_sink", "remove_sink", "recent_roots", "Span",
 ]
 
 _ENABLED = False
-_JAX_SCOPE = False
 _TRACE_IDS = itertools.count(1)  # process-unique, never 0 (0 = "no trace")
 _SINKS: List[Callable[["Span"], None]] = []
 _tls = threading.local()
+# the newest finished roots since the last enable(), oldest first
+RECENT_ROOTS = 4096
+_RECENT: collections.deque = collections.deque(maxlen=RECENT_ROOTS)
+_RECENT_LOCK = threading.Lock()
 
 # injectable for deterministic tests; real spans read it at enter/exit
 clock = time.monotonic
 
 
 def enable() -> None:
-    """Turn the tracer on (module-global; affects all threads)."""
+    """Turn the tracer on (module-global; affects all threads) and start a
+    fresh record of finished roots."""
     global _ENABLED
+    with _RECENT_LOCK:
+        _RECENT.clear()
     _ENABLED = True
 
 
@@ -57,13 +69,6 @@ def enabled() -> bool:
     return _ENABLED
 
 
-def set_jax_scope(on: bool) -> None:
-    """Also wrap every real span in ``jax.named_scope`` (TPU profiler
-    annotation passthrough).  No effect while tracing is disabled."""
-    global _JAX_SCOPE
-    _JAX_SCOPE = bool(on)
-
-
 def add_sink(fn: Callable[["Span"], None]) -> None:
     """Register a callback invoked with every *finished root* span."""
     if fn not in _SINKS:
@@ -73,6 +78,14 @@ def add_sink(fn: Callable[["Span"], None]) -> None:
 def remove_sink(fn: Callable[["Span"], None]) -> None:
     if fn in _SINKS:
         _SINKS.remove(fn)
+
+
+def recent_roots() -> List["Span"]:
+    """The root spans finished since tracing was last enabled, oldest
+    first: the newest ``RECENT_ROOTS`` of them.  Kept after ``disable()``,
+    so a traced stretch can be read once it is over."""
+    with _RECENT_LOCK:
+        return list(_RECENT)
 
 
 def current_trace_id() -> int:
@@ -87,7 +100,7 @@ class Span:
     when tracing is off."""
 
     __slots__ = ("name", "trace_id", "attrs", "t0", "t1", "children",
-                 "metric", "_scope")
+                 "metric", "_annotation")
 
     def __init__(self, name: str, metric: Optional[str], attrs: dict):
         self.name = name
@@ -97,7 +110,7 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.children: List[Span] = []
-        self._scope = None
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -121,19 +134,15 @@ class Span:
         else:
             self.trace_id = next(_TRACE_IDS)
         stack.append(self)
-        if _JAX_SCOPE:
-            import jax
-
-            self._scope = jax.named_scope(self.name)
-            self._scope.__enter__()
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.t0 = clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.t1 = clock()
-        if self._scope is not None:
-            self._scope.__exit__(exc_type, exc, tb)
-            self._scope = None
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._annotation = None
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         stack = getattr(_tls, "stack", None)
@@ -146,6 +155,8 @@ class Span:
         if self.metric is not None:
             self._observe()
         if is_root:
+            with _RECENT_LOCK:
+                _RECENT.append(self)
             for sink in _SINKS:
                 sink(self)
         return False

@@ -1,19 +1,24 @@
 """Observability layer invariants: histogram bucket/percentile math under a
-deterministic clock, span nesting + trace-id propagation, the zero-allocation
-disabled path, slow-log admission order, and the MicroBatcher's atomic
-serving counters under concurrent flushes.
+deterministic clock, span nesting + trace-id propagation, spans as profiler
+host events, the zero-allocation disabled path, the single-host query's span
+tree and its strip and queue-wait attributes, slow-log admission order, and
+the MicroBatcher's atomic serving counters under concurrent flushes.
 """
 
 import threading
+from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import obs
 from repro.core import SketchConfig
+from repro.engine import EngineConfig
+from repro.engine.reduce import strip_bounds
 from repro.index import IndexConfig, SketchIndex
-from repro.index.query import MicroBatcher
+from repro.index.query import MicroBatcher, _segment_rows
 from repro.obs.metrics import Counter, Histogram, MetricsRegistry
 from repro.obs.slowlog import SlowQueryLog
 
@@ -152,6 +157,53 @@ def test_span_nesting_and_trace_id_propagation():
         ch["duration_ms"] for ch in tree["children"]) - 1e-6
 
 
+def test_recent_roots_are_the_roots_finished_since_the_last_enable():
+    obs.enable()
+    with obs.span("a"):
+        with obs.span("b"):
+            pass
+    with obs.span("c"):
+        pass
+    obs.disable()
+    with obs.span("off"):
+        pass
+    # kept after disable(), children only inside their roots
+    assert [r.name for r in obs.trace.recent_roots()] == ["a", "c"]
+    assert [ch.name for ch in obs.trace.recent_roots()[0].children] == ["b"]
+    obs.enable()
+    assert obs.trace.recent_roots() == []
+
+
+def test_recent_roots_keep_the_newest():
+    obs.enable()
+    extra = 3
+    for i in range(obs.trace.RECENT_ROOTS + extra):
+        with obs.span("r", i=i):
+            pass
+    kept = obs.trace.recent_roots()
+    assert len(kept) == obs.trace.RECENT_ROOTS
+    assert [kept[0].attrs["i"], kept[-1].attrs["i"]] == [
+        extra, obs.trace.RECENT_ROOTS + extra - 1]
+
+
+def test_recent_roots_hold_the_roots_of_every_thread():
+    obs.enable()
+
+    def work(i):
+        with obs.span("root", i=i):
+            with obs.span("child"):
+                pass
+
+    ts = [threading.Thread(target=work, args=(i,)) for i in range(8)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+    roots = obs.trace.recent_roots()
+    assert sorted(r.attrs["i"] for r in roots) == list(range(8))
+    assert all([ch.name for ch in r.children] == ["child"] for r in roots)
+
+
 def test_span_metric_feeds_histogram():
     obs.enable()
     with obs.span("timed", metric="timed.ms"):
@@ -195,6 +247,137 @@ def test_disabled_spans_record_nothing():
         pass
     assert obs.REGISTRY.histogram("off.ms").summary()["count"] == before
     assert len(obs.GLOBAL_SLOW_LOG) == 0
+
+
+# ------------------------------------------------- spans in a profiler trace
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under ``jax.profiler`` and return the host plane's lines
+    as lists of (name, start_ns, end_ns), one list per trace line."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = Path(tmp_path).glob("plugins/profile/*/*.xplane.pb")
+    lines = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            lines.extend([(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                          for e in line.events] for line in plane.lines)
+    return lines
+
+
+def test_enabled_spans_are_profiler_host_events_on_their_own_thread(tmp_path):
+    def elsewhere():
+        with obs.span("t.other"):
+            pass
+
+    def work():
+        obs.enable()
+        with obs.span("t.outer", rows=3):
+            with obs.span("t.inner"):
+                jnp.ones(4).block_until_ready()
+        other = threading.Thread(target=elsewhere)
+        other.start()
+        other.join(timeout=30)
+        assert not other.is_alive()
+
+    lines = _profiled(tmp_path, work)
+    ours = [[e for e in line if e[0].startswith("t.")] for line in lines]
+    ours = [line for line in ours if line]
+    assert sorted(len(line) for line in ours) == [1, 2]
+    main = next(line for line in ours if len(line) == 2)
+    (other,) = next(line for line in ours if len(line) == 1)
+    (outer,) = [e for e in main if e[0] == "t.outer"]
+    (inner,) = [e for e in main if e[0] == "t.inner"]
+    # the name alone, nested in the parent's interval, on the parent's line
+    assert outer[1] <= inner[1] <= inner[2] <= outer[2]
+    assert other[0] == "t.other"
+
+
+def test_disabled_spans_emit_no_profiler_event(tmp_path):
+    def work():
+        assert not obs.enabled()
+        with obs.span("t.off"):
+            with obs.span("t.off.child"):
+                jnp.ones(4).block_until_ready()
+
+    names = {e[0] for line in _profiled(tmp_path, work) for e in line}
+    assert not {"t.off", "t.off.child"} & names
+
+
+def test_single_host_span_tree_through_the_batcher(rng):
+    obs.enable()
+    roots = []
+    obs.trace.add_sink(roots.append)
+    X = rng.uniform(0, 1, (150, 128)).astype(np.float32)
+    idx = SketchIndex(CFG, seed=5, index_cfg=IndexConfig(segment_capacity=64),
+                      engine=EngineConfig(col_block=16))
+    idx.ingest(jnp.asarray(X))
+    try:
+        MicroBatcher(idx, max_batch=2, max_wait_ms=1.0).query(
+            jnp.asarray(X[:2]), top_k=3)
+    finally:
+        obs.trace.remove_sink(roots.append)
+
+    def shape(sp):
+        return (sp.name, [shape(c) for c in sp.children])
+
+    (root,) = roots
+    assert shape(root) == (
+        "batcher.query",
+        [("index.query",
+          [("index.sketch", []),
+           ("index.fan.stage1", [("index.fan.dispatch", []),
+                                 ("index.fan.collect", [])])])])
+    (stage1,) = root.find("index.fan.stage1")
+    _, _, col_block = idx.engine.resolve()
+    segments = idx._segments()
+    assert len(segments) == 3
+    assert stage1.attrs["strips"] == sum(
+        len(strip_bounds(_segment_rows(s), col_block)) for s in segments)
+    assert root.attrs["requests"] == 1 and root.attrs["queue_wait_ms"] >= 0.0
+
+
+def test_queue_wait_is_the_mean_over_every_request_of_the_batch(rng):
+    obs.enable()
+    X = rng.uniform(0, 1, (8, 128)).astype(np.float32)
+    idx = SketchIndex(CFG, seed=5)
+    idx.ingest(jnp.asarray(X))
+    mb = MicroBatcher(idx, max_batch=3, max_wait_ms=60_000.0)
+    roots = []
+    fake = [1.0]
+    real = obs.trace.clock
+    obs.trace.clock = lambda: fake[0]
+    obs.trace.add_sink(roots.append)
+    waiters = []
+    try:
+        # two callers join at t=1 and t=2; the third, at t=4, fills the
+        # batch and flushes it at once
+        for t in (1.0, 2.0):
+            fake[0] = t
+            th = threading.Thread(target=mb.query, args=(jnp.asarray(X[:1]),),
+                                  kwargs={"top_k": 3})
+            th.start()
+            waiters.append(th)
+            while mb.stats()["queue_depth"] < len(waiters):
+                threading.Event().wait(0.001)
+        fake[0] = 4.0
+        mb.query(jnp.asarray(X[:1]), top_k=3)
+        for th in waiters:
+            th.join(timeout=60)
+            assert not th.is_alive()
+    finally:
+        obs.trace.clock = real
+        obs.trace.remove_sink(roots.append)
+    (root,) = [r for r in roots if r.name == "batcher.query"]
+    assert root.attrs["requests"] == 3
+    # ((4 - 1) + (4 - 2) + (4 - 4)) / 3 seconds
+    assert root.attrs["queue_wait_ms"] == pytest.approx(5000.0 / 3)
 
 
 # ----------------------------------------------------------------- slow log
