@@ -31,7 +31,8 @@ from .decomposition import interaction_orders
 from .estimators import margin_mle_root
 from .sketch import LpSketch, SketchConfig
 
-__all__ = ["pack_sketch", "pairwise_distances", "pairwise_margin_mle", "knn"]
+__all__ = ["pack_sketch", "pack_right", "pairwise_distances", "pairwise_margin_mle",
+           "knn"]
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -53,6 +54,18 @@ def pack_sketch(sk: LpSketch, cfg: SketchConfig):
     A = jnp.concatenate(A_parts, axis=-1)
     B = jnp.concatenate(B_parts, axis=-1)
     return A, B, sk.norm_pp(p)
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def pack_right(sk: LpSketch, cfg: SketchConfig):
+    """(B, norms): ``pack_sketch``'s right factor and marginal norms alone.
+
+    What a stored corpus segment keeps.  The unused left factor is never
+    built, so a burst of packs dispatched together (a fan's first query
+    packs every sealed segment) holds no dead left factors in device memory
+    until their programs finish.  Same ops as ``pack_sketch``, same bits."""
+    _, B, norms = pack_sketch(sk, cfg)
+    return B, norms
 
 
 @partial(jax.jit, static_argnames=("cfg", "clip", "zero_diag"))

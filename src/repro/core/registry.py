@@ -165,7 +165,9 @@ class EstimatorSpec:
         as one packed matmul (the plain estimator); False = strips call
         ``pairwise`` on raw sketches.
       pairwise: ``(sa, sb, cfg, *, clip=True) -> (n, m)`` strip estimates
-        for raw-sketch estimators (also the dense reference for tests).
+        for raw-sketch estimators (also the dense reference for tests).  It
+        must be traceable (jnp code, ``cfg`` static): the segment fan calls
+        it inside one compiled loop over a segment's strips.
       variance: optional per-pair variance model
         ``(x, y, p, k) -> Var[d_hat]`` (the Lemma-4-style gates).
       capabilities: :class:`RouteCapabilities` the planner consumes.

@@ -24,6 +24,7 @@ from .backends import strip_distances
 __all__ = [
     "streaming_topk",
     "streaming_topk_strips",
+    "scan_topk",
     "stacked_topk_scan",
     "stacked_threshold_scan",
     "merge_topk",
@@ -109,6 +110,57 @@ def streaming_topk_strips(
     return vals, idx
 
 
+def merge_group(n_strips: int, c: int, width: int) -> int:
+    """Strips whose candidates ``scan_topk`` merges at once: the largest
+    divisor of ``n_strips`` whose ``group * c`` candidates fit in one strip's
+    ``width``.  The candidate buffer is then never wider than the distance
+    strip the loop holds anyway, and the merge's top-k runs
+    ``n_strips / group`` times instead of once per strip."""
+    return max(g for g in range(1, n_strips + 1)
+               if n_strips % g == 0 and g * c <= width)
+
+
+def scan_topk(strip: Callable, n_strips: int, init, *, width: int, c: int,
+              k: int) -> Tuple[jax.Array, jax.Array]:
+    """Fold ``n_strips`` strips into the running (rows, k) lists ``init``
+    in one ``lax.scan``: the engine's one compiled strip fold.
+
+    ``strip(i)`` maps a traced strip index to ``(D, live, to_pos)``: the
+    (rows, width) distance estimate, the (width,) live mask, and a map from
+    the strip's local columns to int32 global positions.  Each strip's dead
+    columns are forced to ``+inf`` *after* the estimate (live values stay
+    bit-identical), its best ``c`` per row are taken, and every
+    ``merge_group(n_strips, c, width)`` strips the candidates are merged
+    into the running list.  The running list precedes the candidates and
+    the strips keep their order in the concatenation, so equal values
+    resolve to the lower position exactly as a merge after every strip
+    would: with strips in ascending position order, the dense contract.
+    """
+    group = merge_group(n_strips, c, width)
+
+    def candidates(i):
+        D, live, to_pos = strip(i)
+        D = jnp.where(live[None, :], D, jnp.inf)
+        neg, j = jax.lax.top_k(-D, c)
+        return -neg, to_pos(j)
+
+    def body(carry, g):
+        if group == 1:
+            cand_vals, cand_idx = candidates(g)
+        else:
+            _, cand = jax.lax.scan(
+                lambda _, t: (None, candidates(g * group + t)), None,
+                jnp.arange(group))
+            # (group, rows, c) -> (rows, group * c), strips in order
+            cand_vals, cand_idx = (
+                jnp.moveaxis(x, 0, 1).reshape(x.shape[1], group * c)
+                for x in cand)
+        return merge_topk(*carry, cand_vals, cand_idx, k), None
+
+    (vals, idx), _ = jax.lax.scan(body, init, jnp.arange(n_strips // group))
+    return vals, idx
+
+
 def stacked_topk_scan(
     strip_fn: Callable,
     strips,
@@ -118,7 +170,7 @@ def stacked_topk_scan(
     rows: int,
     top_k: int,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Masked streaming top-k over uniform stacked strips via ``lax.scan``.
+    """Masked streaming top-k over uniform stacked strips (``scan_topk``).
 
     The strip-unrolled folds (``streaming_topk_strips``) compile one program
     per strip count, so a traced fan over a large corpus pays compile time
@@ -139,22 +191,18 @@ def stacked_topk_scan(
     """
     n_strips, col_block = mask.shape
     k = min(top_k, n_strips * col_block)
-    c = min(k, col_block)
     init = (
         jnp.full((rows, k), jnp.inf, jnp.float32),
         jnp.full((rows, k), _IDX_SENTINEL, jnp.int32),
     )
 
-    def body(carry, xs):
-        strip_slice, m, p = xs
-        D = strip_fn(strip_slice)
-        D = jnp.where(m[None, :], D, jnp.inf)
-        neg, j = jax.lax.top_k(-D, c)
-        vals, idx = merge_topk(*carry, -neg, p[j].astype(jnp.int32), k)
-        return (vals, idx), None
+    def strip(i):
+        p = pos[i]
+        D = strip_fn(jax.tree_util.tree_map(lambda x: x[i], strips))
+        return D, mask[i], lambda j: p[j].astype(jnp.int32)
 
-    (vals, idx), _ = jax.lax.scan(body, init, (strips, mask, pos))
-    return vals, idx
+    return scan_topk(strip, n_strips, init, width=col_block,
+                     c=min(k, col_block), k=k)
 
 
 def stacked_threshold_scan(

@@ -1,16 +1,18 @@
 """Query planning: fan the engine's fused reductions across segments.
 
-``fan_topk`` streams each segment through the engine's strip machinery
-(packed-matmul strips when the resolved estimator spec declares
-``uses_packed``, the spec's own strip function otherwise) with tombstones
-masked to ``+inf`` *after* the strip estimate (``where`` keeps live-row values
-bit-identical), then folds the per-segment candidate lists with the engine's
-``merge_topk``.  Tie-breaking matches a dense ``knn`` over the equivalent
-live corpus exactly: within a segment the engine resolves ties to the lowest
-local column; across segments the running candidate list always precedes the
-newer segment's candidates in the merge concatenation, and segments are
-visited in creation (= ingest) order — so equal distances resolve to the
-earliest-ingested live row, same as dense.
+``fan_topk`` runs one compiled fold per segment: the engine's ``scan_topk``
+over the segment's full-width strips (packed-matmul strips when the resolved
+estimator spec declares ``uses_packed``, the spec's own strip function
+otherwise), cut from the segment's operands as stored, with tombstones masked
+to ``+inf`` *after* the strip estimate (``where`` keeps live-row values
+bit-identical) and the strips' top candidates merged into the running list
+with ``merge_topk``; a segment's one narrower strip, if any, follows through
+the same program at its width.  Tie-breaking matches a dense ``knn`` over the
+equivalent live corpus exactly: within a segment the engine resolves ties to
+the lowest local column; across segments the running candidate list always
+precedes the newer segment's candidates in the merge concatenation, and
+segments are visited in creation (= ingest) order — so equal distances
+resolve to the earliest-ingested live row, same as dense.
 
 ``threshold_scan`` routes the same masked strips through the engine's
 threshold criterion, yielding (query_row, row_id) pairs.
@@ -23,6 +25,7 @@ group — one sketch call + one fan per batch instead of one per request.
 from __future__ import annotations
 
 import threading
+from functools import partial
 from typing import List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -31,11 +34,11 @@ import numpy as np
 
 from repro import obs
 from repro.core import registry
-from repro.core.pairwise import pack_sketch
+from repro.core.pairwise import pack_right, pack_sketch
 from repro.core.registry import EstimatorSpec
 from repro.core.sketch import LpSketch, SketchConfig
 from repro.engine import EngineConfig, strip_distances
-from repro.engine.reduce import merge_topk, strip_bounds
+from repro.engine.reduce import scan_topk, strip_bounds
 from repro.obs.metrics import REGISTRY
 
 from .segment import ActiveSegment, SealedSegment
@@ -104,31 +107,43 @@ def _pack_query(qsk: LpSketch, cfg: SketchConfig, spec: EstimatorSpec):
     return Aq, nq
 
 
+def _segment_operands(seg: Segment, cfg: SketchConfig, spec: EstimatorSpec):
+    """The segment's strip operands as stored: the packed right factors
+    ``(B, nb)`` when the spec ``uses_packed``, the raw ``(U, moments)``
+    otherwise.  Full-height views, never a copy of a sealed segment."""
+    if spec.uses_packed:
+        if isinstance(seg, ActiveSegment):
+            return pack_right(seg.as_sketch(), cfg)
+        return seg.packed(cfg)
+    sk = seg.as_sketch() if isinstance(seg, ActiveSegment) else seg.sketch
+    return sk.U, sk.moments
+
+
+def _strip_estimate(q_ops, seg_strip, cfg: SketchConfig, spec: EstimatorSpec,
+                    backend: str) -> jax.Array:
+    """(q, w) distance strip from one column slice of the segment operands;
+    ``q_ops`` is the packed ``(Aq, nq)`` or the query sketch.  Traceable:
+    the compiled fold calls it inside its loop body."""
+    if spec.uses_packed:
+        Aq, nq = q_ops
+        B, nb = seg_strip
+        return strip_distances(Aq, B, nq, nb, backend=backend, clip=True)
+    U, moments = seg_strip
+    return spec.pairwise(q_ops, LpSketch(U=U, moments=moments), cfg,
+                         clip=True)
+
+
 def _segment_strip_fn(qsk: LpSketch, q_packed, seg: Segment,
                       cfg: SketchConfig, spec: EstimatorSpec, backend: str):
     """strip(c0, c1) -> (q, c1-c0) masked distance strip for one segment."""
     mask = seg.mask()
-    if spec.uses_packed:
-        if isinstance(seg, ActiveSegment):
-            _, B, nb = pack_sketch(seg.as_sketch(), cfg)
-        else:
-            B, nb = seg.packed(cfg)
-        Aq, nq = q_packed
+    ops = _segment_operands(seg, cfg, spec)
+    q_ops = q_packed if spec.uses_packed else qsk
 
-        def strip(c0: int, c1: int) -> jax.Array:
-            D = strip_distances(Aq, B[c0:c1], nq, nb[c0:c1],
-                                backend=backend, clip=True)
-            return jnp.where(mask[c0:c1][None, :], D, jnp.inf)
-    else:
-        seg_sk = seg.as_sketch() if isinstance(seg, ActiveSegment) else seg.sketch
-
-        def strip(c0: int, c1: int) -> jax.Array:
-            D = spec.pairwise(
-                qsk,
-                LpSketch(U=seg_sk.U[c0:c1], moments=seg_sk.moments[c0:c1]),
-                cfg, clip=True,
-            )
-            return jnp.where(mask[c0:c1][None, :], D, jnp.inf)
+    def strip(c0: int, c1: int) -> jax.Array:
+        D = _strip_estimate(q_ops, tuple(x[c0:c1] for x in ops), cfg, spec,
+                            backend)
+        return jnp.where(mask[c0:c1][None, :], D, jnp.inf)
 
     return strip
 
@@ -137,21 +152,66 @@ def _segment_rows(seg: Segment) -> int:
     return seg.capacity if isinstance(seg, ActiveSegment) else seg.n
 
 
+def _strip_plan(n: int, col_block: int) -> Tuple[int, Optional[int]]:
+    """(full-width strip count, width of the one narrower or wider last
+    strip or None) for ``strip_bounds(n, col_block)``: only the last strip
+    can differ from ``col_block``, and ``strip_bounds`` absorbs a width-1
+    tail into it."""
+    widths = [c1 - c0 for c0, c1 in strip_bounds(n, col_block)]
+    n_full = sum(w == col_block for w in widths)
+    return n_full, (widths[-1] if len(widths) > n_full else None)
+
+
+@partial(jax.jit, static_argnames=("cfg", "spec", "backend", "start",
+                                   "width", "n_strips", "c", "k"))
+def _fold_strips(vals, idx, q_ops, seg_ops, mask, base, *, cfg, spec,
+                 backend, start, width, n_strips, c, k):
+    """Fold ``n_strips`` strips of ``width`` columns, the first at local
+    column ``start``, into the running (q, k) lists, in one program: the
+    engine's ``scan_topk`` with each strip cut from the operands as passed
+    (``dynamic_slice``, no stacked or padded copy of the segment) and its
+    columns globalized at ``base`` (traced, so every segment of one shape
+    shares the program)."""
+
+    def strip(i):
+        c0 = start + i * width
+        cut = tuple(jax.lax.dynamic_slice_in_dim(x, c0, width)
+                    for x in seg_ops)
+        return (_strip_estimate(q_ops, cut, cfg, spec, backend),
+                jax.lax.dynamic_slice_in_dim(mask, c0, width),
+                lambda j: (j + (base + c0)).astype(jnp.int32))
+
+    return scan_topk(strip, n_strips, (vals, idx), width=width, c=c, k=k)
+
+
 def _fold_segment_topk(vals, idx, qsk, q_packed, seg: Segment,
                        cfg: SketchConfig, spec: EstimatorSpec, backend: str,
                        col_block: int, base: int, k: int):
     """Fold one segment's strips into a running (q, k) candidate list, with
-    columns globalized at ``base``.  The single-host fan and the sharded
-    stage-1 fans both run THIS loop, so their per-segment candidates are
-    identical by construction."""
+    columns globalized at ``base``: one compiled fold per segment over its
+    full-width strips, then its one remainder strip (if any) through the
+    same program at that width.  The programs are keyed on shapes,
+    ``col_block``, ``k``, backend and estimator, never on the segment or
+    its ``base``.  The running list precedes the strips in the merge and
+    the strips keep column order, so ties resolve to the lowest column as
+    in a dense ``top_k``.  The single-host fan and the sharded stage-1
+    fans both run THIS fold, so their per-segment candidates are identical
+    by construction."""
     n = _segment_rows(seg)
-    strip = _segment_strip_fn(qsk, q_packed, seg, cfg, spec, backend)
+    n_full, tail = _strip_plan(n, col_block)
     c = min(k, n)
-    for c0, c1 in strip_bounds(n, col_block):
-        D = strip(c0, c1)
-        neg, j = jax.lax.top_k(-D, min(c, c1 - c0))
-        cand_idx = (j + (base + c0)).astype(jnp.int32)
-        vals, idx = merge_topk(vals, idx, -neg, cand_idx, k)
+    q_ops = q_packed if spec.uses_packed else qsk
+    ops = _segment_operands(seg, cfg, spec)
+    mask = seg.mask()
+    base = np.int32(base)
+    for start, width, n_strips in ((0, col_block, n_full),
+                                   (n_full * col_block, tail, 1)):
+        if width is None or n_strips == 0:
+            continue
+        vals, idx = _fold_strips(vals, idx, q_ops, ops, mask, base, cfg=cfg,
+                                 spec=spec, backend=backend, start=start,
+                                 width=width, n_strips=n_strips,
+                                 c=min(c, width), k=k)
     return vals, idx
 
 
@@ -203,9 +263,10 @@ def fan_topk(
     top_k: int,
     estimator: str = registry.DEFAULT_ESTIMATOR,
     engine: Optional[EngineConfig] = None,
-) -> Tuple[jax.Array, np.ndarray]:
+) -> Tuple[np.ndarray, np.ndarray]:
     """(distances (q, k), row_ids (q, k)) over all live rows, ascending,
-    k = min(top_k, total live rows).  Dead/padded rows never surface."""
+    k = min(top_k, total live rows), both host arrays: collect copies the
+    candidate lists to the host anyway.  Dead/padded rows never surface."""
     spec = registry.resolve(estimator, p=cfg.p,
                             projection=cfg.projection.family)
     _check_top_k(top_k)
@@ -214,7 +275,7 @@ def fan_topk(
     n_live = sum(seg.live_count for seg in segments)
     k_out = min(top_k, n_live)
     if k_out == 0:
-        return (jnp.zeros((q, 0), jnp.float32), np.zeros((q, 0), np.int64))
+        return (np.zeros((q, 0), np.float32), np.zeros((q, 0), np.int64))
 
     # merge in global-position space (segment base + local column): position
     # order == ingest order, which is the dense corpus's tie-break order
@@ -224,13 +285,14 @@ def fan_topk(
     id_map: List[np.ndarray] = []
     with obs.span("index.fan.stage1", metric="index.stage1_dense_ms",
                   mode="single", segments=len(segments)) as sp:
-        # jax dispatch is async: the dispatch span times the host's strip
-        # loop, and the device work it queued is waited for under collect
+        # jax dispatch is async: the dispatch span times the host's
+        # per-segment folds, and the device work they queued is waited for
+        # under collect
         with obs.span("index.fan.dispatch"):
             vals = jnp.full((q, k_run), jnp.inf, jnp.float32)
             idx = jnp.full((q, k_run), _IDX_SENTINEL, jnp.int32)
             q_packed = _pack_query(qsk, cfg, spec)
-            strips = 0
+            strips = fold_programs = eager_strips = 0
             for seg in segments:
                 n = _segment_rows(seg)
                 vals, idx = _fold_segment_topk(vals, idx, qsk, q_packed, seg,
@@ -239,15 +301,20 @@ def fan_topk(
                 id_map.append(seg.row_ids[:n])
                 base += n
                 if sp:
-                    strips += len(strip_bounds(n, col_block))
+                    n_full, tail = _strip_plan(n, col_block)
+                    fold_programs += n_full > 0
+                    eager_strips += tail is not None
+                    strips += n_full + (tail is not None)
         if sp:
-            sp.set(strips=strips)
+            sp.set(strips=strips, fold_programs=fold_programs,
+                   eager_strips=eager_strips)
         with obs.span("index.fan.collect"):
             pos_to_id = (np.concatenate(id_map) if id_map
                          else np.zeros(0, np.int64))
-            k_out = _finite_k(np.asarray(vals), k_out)
+            vals_h = np.asarray(vals)
+            k_out = _finite_k(vals_h, k_out)
             pos = np.asarray(idx[:, :k_out])
-    return vals[:, :k_out], pos_to_id[pos]
+    return vals_h[:, :k_out], pos_to_id[pos]
 
 
 def threshold_scan(

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.pairwise import pack_sketch
+from repro.core.pairwise import pack_right
 from repro.core.sketch import LpSketch, SketchConfig
 from repro.obs.metrics import REGISTRY
 
@@ -167,8 +167,7 @@ class SealedSegment:
     def packed(self, cfg: SketchConfig):
         """(B, nb): cached right factor + marginal norms for plain strips."""
         if self._packed is None:
-            _, B, nb = pack_sketch(self.sketch, cfg)
-            self._packed = (B, nb)
+            self._packed = pack_right(self.sketch, cfg)
         return self._packed
 
     def mask(self) -> jax.Array:

@@ -224,8 +224,10 @@ def _shard_candidates(qsk, q_packed, group, cfg, spec, backend,
     """Stage 1: one shard's candidate list in global-position space.
 
     Runs the exact per-segment fold the single-host fan runs
-    (``_fold_segment_topk``), restricted to this shard's segments — the
-    per-segment candidates are identical by construction."""
+    (``_fold_segment_topk``: one compiled fold per segment), restricted to
+    this shard's segments — the per-segment candidates are identical by
+    construction, and segments are folded in ingest order, so ties resolve
+    to the lowest global position within the shard."""
     shard_rows = sum(_segment_rows(seg) for _, seg in group)
     k = min(top_k, shard_rows)
     vals = jnp.full((q, k), jnp.inf, jnp.float32)

@@ -48,7 +48,7 @@ import numpy as np
 
 from repro import obs
 from repro.core import registry
-from repro.core.pairwise import pack_sketch
+from repro.core.pairwise import pack_right
 from repro.core.sketch import sketch
 from repro.index.sharded import sharded_fan_topk, sharded_threshold_scan
 from repro.obs.metrics import REGISTRY
@@ -95,11 +95,10 @@ class _ReplicaSegment:
 
     def packed(self, cfg):
         """(B, nb) right factors, built lazily from the replica-local sketch
-        — same deterministic ``pack_sketch`` program as seal time, so the
+        — same deterministic ``pack_right`` program as seal time, so the
         factors match the primary's bit for bit."""
         if self._packed is None:
-            _, B, nb = pack_sketch(self.sketch, cfg)
-            self._packed = (B, nb)
+            self._packed = pack_right(self.sketch, cfg)
         return self._packed
 
     def mask(self) -> jax.Array:

@@ -231,6 +231,30 @@ def test_micro_batcher_coalesces(rng):
     assert mb.batches_run < 16  # coalesced, not one engine pass per caller
 
 
+def test_micro_batcher_hands_out_host_rows(rng):
+    """Each caller gets its rows of the batch as host numpy arrays (the
+    fan hands back the host copy it collects), the same bits as the direct
+    query of the same batch."""
+    X = np.asarray(rows_of(rng, 120))
+    index = make_index(capacity=50)
+    index.ingest(jnp.asarray(X))
+    d_ref, i_ref = index.query(jnp.asarray(X[:2]), top_k=4)
+    mb = MicroBatcher(index, max_batch=2, max_wait_ms=60_000.0)
+    results = {}
+    threads = [threading.Thread(
+        target=lambda i=i: results.__setitem__(i, mb.query(X[i], top_k=4)))
+        for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert mb.batches_run == 1
+    for i, (d, ids) in results.items():
+        assert type(d) is np.ndarray and d.dtype == np.float32
+        np.testing.assert_array_equal(d[0], np.asarray(d_ref[i]))
+        np.testing.assert_array_equal(ids[0], i_ref[i])
+
+
 def test_micro_batcher_timeout_flush(rng):
     X = np.asarray(rows_of(rng, 100))
     index = make_index(capacity=100)

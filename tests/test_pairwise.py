@@ -11,11 +11,13 @@ from repro.core import (
     estimate_margin_mle,
     exact_pairwise_lp,
     knn,
+    pack_sketch,
     pairwise_distances,
     pairwise_margin_mle,
     sketch,
     variance_plain,
 )
+from repro.core.pairwise import pack_right
 
 KEY = jax.random.key(3)
 
@@ -35,6 +37,19 @@ def test_pairwise_equals_per_pair(strategy, p):
         for j in range(6):
             e = float(estimate(sk.row(i), sk.row(j), cfg, clip=False)[0])
             np.testing.assert_allclose(D[i, j], e, rtol=2e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("strategy", ["basic", "alternative"])
+@pytest.mark.parametrize("p", [4, 6])
+def test_pack_right_is_pack_sketch_right_factor(strategy, p):
+    # stored segments pack with pack_right, queries with pack_sketch: the
+    # right factor and norms must carry the same bits either way
+    cfg = SketchConfig(p=p, k=32, strategy=strategy, block_d=64)
+    sk = _sk(jax.random.uniform(jax.random.key(2), (40, 128)), cfg)
+    _, B, nb = pack_sketch(sk, cfg)
+    B_r, nb_r = pack_right(sk, cfg)
+    np.testing.assert_array_equal(np.asarray(B_r), np.asarray(B))
+    np.testing.assert_array_equal(np.asarray(nb_r), np.asarray(nb))
 
 
 def test_pairwise_symmetry_and_diag():

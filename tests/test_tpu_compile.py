@@ -21,7 +21,10 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.core import registry
 from repro.core.distributed import stacked_threshold_shards, stacked_topk_shards
+from repro.core.sketch import SketchConfig
+from repro.index.query import _fold_strips
 from repro.kernels.pairwise_lp.kernel import pairwise_lp_call
 from repro.kernels.power_project.kernel import power_project_call
 
@@ -73,6 +76,24 @@ def test_pairwise_lp_compiles_for_v5e(one_chip, rows):
     args = (_shape((rows, W), one_chip), _shape((COL_BLOCK, W), one_chip),
             _shape((rows,), one_chip), _shape((COL_BLOCK,), one_chip))
     _assert_kernel(jax.jit(pairwise_lp_call).lower(*args).compile())
+
+
+def test_segment_fold_compiles_for_v5e(one_chip):
+    # one 65,536-row segment of packed factors, folded in one program with
+    # the kernel inside the loop; the segment is an argument, never copied
+    cfg = SketchConfig(p=4, k=256, block_d=960)
+    spec = registry.resolve(registry.PLAIN, p=cfg.p,
+                            projection=cfg.projection.family)
+    n, q, k = 64 * COL_BLOCK, 64, 10
+    args = (_shape((q, k), one_chip), _shape((q, k), one_chip, jnp.int32),
+            (_shape((q, W), one_chip), _shape((q,), one_chip)),
+            (_shape((n, W), one_chip), _shape((n,), one_chip)),
+            _shape((n,), one_chip, jnp.bool_), _shape((), one_chip, jnp.int32))
+    compiled = _fold_strips.lower(
+        *args, cfg=cfg, spec=spec, backend="pallas", start=0, width=COL_BLOCK,
+        n_strips=64, c=k, k=k).compile()
+    _assert_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < n * W * 4 // 16
 
 
 def test_power_project_compiles_for_v5e(one_chip):
