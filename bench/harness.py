@@ -11,6 +11,7 @@ import gc
 import math
 import shutil
 import sys
+import tempfile
 import threading
 import time
 from pathlib import Path
@@ -83,6 +84,12 @@ def chips_for(cell: Cell, require_chip: bool) -> list:
     return devices[:cell.chips]
 
 
+def peak_bytes(devices) -> list:
+    """Each device's ``peak_bytes_in_use`` (None where it keeps no count)."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices]
+
+
 def seeds(seed: int) -> Dict[str, int]:
     """Independent 32-bit streams from one seed of any size."""
     words = np.random.SeedSequence(int(seed)).generate_state(4)
@@ -102,17 +109,35 @@ def make_queries(gen, key, rows: int, batch_rows: int, dim: int,
     return np.asarray(jnp.maximum(X + noise * eps, 0.0)), src
 
 
+def counter_values() -> Dict[str, int]:
+    """Every counter of the program's metrics registry, by name."""
+    from repro.obs.metrics import REGISTRY, Counter
+
+    out = {}
+    for name in REGISTRY.names():
+        m = REGISTRY.get(name)
+        if isinstance(m, Counter):
+            out[name] = m.value
+    return out
+
+
 class Window:
-    """What the per-layer readers see of one window (``bench/metrics``)."""
+    """What the per-layer readers see of one window (``bench/metrics``):
+    among others ``counters``, the window's change of every counter of the
+    program's registry (name -> delta)."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
 
 
 class _WindowMarker:
-    """Starts the profiler and marks the traced window with one host event,
-    entered and left on a thread of its own; ``stop`` (idempotent) ends the
-    event and the trace.  ``bounds`` is the window on the span clock."""
+    """Marks the traced window with one host event, entered and left on a
+    thread of its own; ``stop`` (idempotent) ends the event, disables the
+    program's tracing and stops the profiler.  Call it from the thread that
+    started the profiler: stopped from another thread, the profiler took
+    about three times as long per event kept (PERF.md).  ``bounds`` is the
+    traced part on the span clock, ``stop_s`` the seconds the profiler took
+    to stop."""
 
     def __init__(self):
         from repro.obs import trace as obs_trace
@@ -123,6 +148,7 @@ class _WindowMarker:
         self._entered = threading.Event()
         self._thread = threading.Thread(target=self._hold, daemon=True)
         self.bounds = None
+        self.stop_s = None
         self._t0 = None
         self._thread.start()
         self._entered.wait()
@@ -134,13 +160,17 @@ class _WindowMarker:
             self._stop.wait()
 
     def stop(self):
+        from repro import obs
+
         with self._lock:
             if self.bounds is not None:
                 return
             self._stop.set()
             self._thread.join()
             self.bounds = (self._t0, self._clock())
+            obs.disable()
             jax.profiler.stop_trace()
+            self.stop_s = self._clock() - self.bounds[1]
 
 
 def _finite(v):
@@ -180,7 +210,7 @@ def run_cell(bench: Benchmark, workload: str, *, seed: int, seconds: float,
     gen = bench.module("data", cfg["data"]["generator"]).batch
     ref = bench.module("reference", cfg["reference"])
     system = System(cfg, traffic, gen, index_seed=s["index"],
-                    data_key=jax.random.key(s["data"]))
+                    data_key=jax.random.key(s["data"]), devices=devices)
     log(f"cell {workload}: {cfg['rows']} rows x {cfg['dim']}, "
         f"{len(devices)} x {devices[0].device_kind}, seed {seed}")
 
@@ -207,34 +237,44 @@ def run_cell(bench: Benchmark, workload: str, *, seed: int, seconds: float,
 
     rows_c = REGISTRY.counter("batcher.rows")
     batches_c = REGISTRY.counter("batcher.batches")
-    trace_dir = Path(bench.root) / TRACE_DIR
     if trace:
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        obs.enable(jax_scope=False)
+        # a directory of this run's own: runs in one checkout may overlap
+        (Path(bench.root) / TRACE_DIR).mkdir(exist_ok=True)
+        trace_dir = Path(tempfile.mkdtemp(prefix="run-",
+                                          dir=Path(bench.root) / TRACE_DIR))
+        obs.enable()
         obs.trace.add_sink(sink)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
     rows0, batches0 = rows_c.value, batches_c.value
+    counters0 = counter_values()
     setup_s = time.perf_counter() - t_process
 
     # ---------------------------------------------------------- window
     send = (lambda row: fd.query(row, top_k=traffic["top_k"],
                                  estimator=traffic["estimator"]))
     marker = _WindowMarker() if trace else None
+    cut = cfg.get("trace_window_s")
     t_win = time.perf_counter()
     requests, t_open, t_close, t_drained = loop.run_closed_loop(
-        send, pool, orders, seconds, annotate=trace)
+        send, pool, orders, seconds, annotate=trace,
+        at=(cut, marker.stop) if trace and cut is not None else None)
     if trace:
+        t_loop = time.perf_counter()
         marker.stop()
+        log(f"traced part {marker.bounds[1] - marker.bounds[0]:.3f} s; the "
+            f"profiler stopped in {marker.stop_s:.3f} s, "
+            f"{time.perf_counter() - t_loop:.3f} s of it after the loop")
     window_compiles = watch.between(t_win, t_drained)
     rows_d, batches_d = rows_c.value - rows0, batches_c.value - batches0
+    counters = {n: v - counters0.get(n, 0)
+                for n, v in counter_values().items()}
     if trace:
         obs.trace.remove_sink(sink)
-        obs.disable()
 
     # ------------------------------------------------------- read-outs
-    mem = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
+    mem = peak_bytes(devices)
     if any(m is None for m in mem):
         if require_chip:
             raise RuntimeError("the device reports no peak_bytes_in_use")
@@ -262,6 +302,8 @@ def run_cell(bench: Benchmark, workload: str, *, seed: int, seconds: float,
     }
     log(f"window: {len(requests)} requests, {failed} failed, "
         f"{batches_d} batches, {rows_d} rows, compiles {window_compiles}")
+    log("window counters: " + ", ".join(
+        f"{n} {v}" for n, v in sorted(counters.items()) if v))
 
     # ------------------------------------------------------ comparison
     sk = cfg["sketch"]
@@ -310,6 +352,7 @@ def run_cell(bench: Benchmark, workload: str, *, seed: int, seconds: float,
             rows=rows_d, batches=batches_d, spans=spans,
             traced_batches=_batch_weights(spans, lo, hi),
             trace=summary, compiles=window_compiles, live_rows=live_rows,
+            counters=counters,
             chips=len(devices),
             peak=roofline.peaks(dev.device_kind) if require_chip else None,
             packed_width=(sk["p"] - 1) * sk["k"])
@@ -320,6 +363,7 @@ def run_cell(bench: Benchmark, workload: str, *, seed: int, seconds: float,
                                              "unit": m.unit}
         result["device"]["busy_s"] = summary.mean_busy_s
         result["device"]["window_s"] = summary.window_s
+        result["device"]["trace_events"] = summary.events
         result["breakdown"] = {"device_ops": summary.device_ops,
                                "idle_gaps": summary.idle_gaps}
     else:

@@ -1,5 +1,10 @@
 """The closed loop: each client sends one request, waits for its answer,
-then sends the next, until the window closes.
+then sends the next, until the window closes and the round open at the
+close is complete: a client that finds the window closed sends on while it
+has sent fewer requests than the most any client had sent when the close
+was first seen.  Clients that each wait for their answer move in rounds,
+so a close that falls while some have resubmitted and others have not
+would otherwise leave the last batch short of its round.
 
 A request's latency runs from its send to its answer as a host numpy array.
 Requests sent before the window closes are waited for after it; one that
@@ -13,7 +18,7 @@ import math
 import threading
 import time
 from contextlib import nullcontext
-from typing import Callable, List, Optional
+from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
@@ -45,17 +50,33 @@ def client_orders(rng: np.random.Generator, pool: int, clients: int):
 
 
 def run_closed_loop(send: Callable, pool: np.ndarray, orders, seconds: float,
-                    *, drain_s: float = 60.0, annotate: bool = False):
+                    *, drain_s: float = 60.0, annotate: bool = False,
+                    at: Optional[Tuple[float, Callable[[], None]]] = None):
     """Drive ``send(row (1, d)) -> (values, ids)`` from ``len(orders)``
-    client threads for ``seconds``; returns (requests, t_open, t_close,
-    t_drained) on ``time.perf_counter``.
+    client threads for ``seconds`` and the round open at the close;
+    returns (requests, t_open, t_close, t_drained) on
+    ``time.perf_counter``.
 
-    A client that has not answered ``drain_s`` after the window closed is
-    left behind: its request stays unanswered and counts as failed."""
+    ``at=(s, fn)`` calls ``fn()`` from the calling thread ``s`` seconds
+    after the window opened, or when the clients are done if that is
+    sooner, while the clients run on.  A client that has not answered
+    ``drain_s`` after the window closed (or after ``fn`` returned, if
+    later) is left behind: its request stays unanswered and counts as
+    failed."""
     requests: List[Request] = []
     lock = threading.Lock()
     go = threading.Event()
     bounds = {}
+    sent = [0] * len(orders)
+    last_round = []
+
+    def more(c: int) -> bool:
+        with lock:
+            if time.perf_counter() < bounds["close"]:
+                return True
+            if not last_round:
+                last_round.append(max(sent))
+            return sent[c] < last_round[0]
 
     def trace(name):
         if not annotate:
@@ -68,12 +89,13 @@ def run_closed_loop(send: Callable, pool: np.ndarray, orders, seconds: float,
         order = orders[c]
         go.wait()
         i = 0
-        while time.perf_counter() < bounds["close"]:
+        while more(c):
             qi = int(order[i % len(order)])
             i += 1
             req = Request(client=c, query=qi, t_send=time.perf_counter())
             with lock:
                 requests.append(req)
+                sent[c] += 1
             try:
                 with trace("bench.request"):
                     vals, ids = send(pool[qi:qi + 1])
@@ -92,6 +114,12 @@ def run_closed_loop(send: Callable, pool: np.ndarray, orders, seconds: float,
     bounds["close"] = bounds["open"] + seconds
     go.set()
     deadline = bounds["close"] + drain_s
+    if at is not None:
+        t_at, fn = at
+        for t in threads:
+            t.join(max(0.0, bounds["open"] + t_at - time.perf_counter()))
+        fn()
+        deadline = max(deadline, time.perf_counter() + drain_s)
     for t in threads:
         t.join(max(0.0, deadline - time.perf_counter()))
     drained = time.perf_counter()

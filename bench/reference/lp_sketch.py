@@ -145,23 +145,28 @@ def _fold_block(carry, key, b, n_rows, R, Uq, Mq, served, *, gen, n, d,
     return -neg, jnp.take_along_axis(i, pos, axis=1), at_served
 
 
+GATHER_ROWS = 32
+
+
 def rows_at(gen, key, ids: np.ndarray, *, n: int, d: int, gen_params):
-    """The corpus rows with these ids (any order), made again on device."""
+    """The corpus rows with these ids (any order), made again on device,
+    ``GATHER_ROWS`` at a time from each block that holds some: one compiled
+    gather, whatever the ids."""
+    width = GATHER_ROWS
     ids = np.asarray(ids, np.int64)
     blocks = ids // n
-    width = 8
-    for b in np.unique(blocks):
-        width = max(width, int(np.sum(blocks == b)))
-    width = 1 << (width - 1).bit_length()
     take = _take_rows(gen, n, d, tuple(sorted(gen_params.items())), width)
     parts, where = [], np.empty(len(ids), np.int64)
-    for slot, b in enumerate(np.unique(blocks)):
+    for b in np.unique(blocks):
         sel = np.flatnonzero(blocks == b)
-        local = np.zeros(width, np.int32)
-        local[:len(sel)] = ids[sel] % n
-        parts.append(take(key, jnp.int32(b), jnp.asarray(local)))
-        where[sel] = slot * width + np.arange(len(sel))
-    return jnp.take(jnp.concatenate(parts), jnp.asarray(where), axis=0)
+        for c0 in range(0, len(sel), width):
+            part = sel[c0:c0 + width]
+            local = np.zeros(width, np.int32)
+            local[:len(part)] = ids[part] % n
+            where[part] = len(parts) * width + np.arange(len(part))
+            parts.append(take(key, jnp.int32(b), jnp.asarray(local)))
+    rows = np.concatenate(jax.device_get(parts))
+    return jnp.asarray(rows[where])
 
 
 _TAKES: dict = {}

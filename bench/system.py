@@ -1,5 +1,9 @@
 """The system under test, built from a configuration through the program's
 public entry points: ``SketchIndex`` for ingest, ``FrontDoor`` for queries.
+A configuration whose ``index`` names ``shards`` builds a
+``ShardedSketchIndex`` on a serving mesh of that many of the cell's chips
+instead, and refuses to serve unless ingest left every row on a shard, the
+shards equal and the stacked fan accepted.
 
 Set-up warms every program the window will run before the window opens:
 ingest on a throwaway index, and each batch size the traffic forms (its
@@ -19,13 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sketch import SketchConfig
-from repro.index import IndexConfig, SketchIndex
+from repro.index import IndexConfig, ShardedSketchIndex, SketchIndex
+from repro.launch.mesh import make_serving_mesh
 from repro.serve import FrontDoor
 
 
 class System:
     def __init__(self, config: dict, traffic: dict, gen, *, index_seed: int,
-                 data_key):
+                 data_key, devices: Optional[list] = None):
         self.config = config
         self.traffic = traffic
         self.gen = gen
@@ -38,6 +43,12 @@ class System:
         ix = config["index"]
         self.batch_rows = ix["ingest_batch"]
         self.index_cfg = IndexConfig(segment_capacity=ix["segment_rows"])
+        self.shards = ix.get("shards")
+        if self.shards is not None and not (
+                devices and 1 <= self.shards <= len(devices)):
+            raise ValueError(f"{self.shards} shards need as many of the "
+                             f"cell's devices; have {devices}")
+        self.devices = devices
         self.rows = config["rows"]
         self.dim = config["dim"]
         self.index = None
@@ -46,8 +57,13 @@ class System:
     # ------------------------------------------------------------ building
 
     def new_index(self) -> SketchIndex:
-        return SketchIndex(self.cfg, seed=self.index_seed,
-                           index_cfg=self.index_cfg)
+        if self.shards is None:
+            return SketchIndex(self.cfg, seed=self.index_seed,
+                               index_cfg=self.index_cfg)
+        mesh = make_serving_mesh(self.shards,
+                                 devices=self.devices[:self.shards])
+        return ShardedSketchIndex(self.cfg, seed=self.index_seed,
+                                  index_cfg=self.index_cfg, mesh=mesh)
 
     def corpus_batch(self, b: int, n: Optional[int] = None):
         X = self.gen(self.data_key, jnp.int32(b), n=self.batch_rows,
@@ -87,7 +103,28 @@ class System:
         if self.index.n_live != self.rows:
             raise RuntimeError(f"{self.index.n_live} live rows after ingest, "
                                f"want {self.rows}")
+        if self.shards is not None:
+            self.check_placement()
         return secs
+
+    def check_placement(self) -> None:
+        """The sharded deployment as configured: every row sealed onto a
+        shard, the shards equal, and the stacked fan accepted by the mesh."""
+        st = self.index.stats()
+        rows = st["rows_per_shard"]
+        faults = []
+        if st["stacked_fan_declined"] is not None:
+            faults.append(f"stacked fan declined: {st['stacked_fan_declined']}")
+        if len(set(rows)) != 1 or rows[0] == 0:
+            faults.append(f"rows per shard {rows}, want equal and non-zero")
+        if self.index.active.size:
+            faults.append(f"{self.index.active.size} rows left in the active "
+                          "segment")
+        unplaced = sum(1 for seg in self.index.sealed if seg.shard is None)
+        if unplaced:
+            faults.append(f"{unplaced} sealed segments on no shard")
+        if faults:
+            raise RuntimeError("sharded ingest: " + "; ".join(faults))
 
     def serve(self) -> FrontDoor:
         fd = self.config["front_door"]
@@ -133,7 +170,9 @@ class System:
 
     def stored_sketch(self, ids: np.ndarray):
         """The index's stored (U, moments) rows for these ids.  Ids are
-        ingest positions here: nothing is deleted, segments fill in order."""
+        ingest positions here: nothing is deleted, segments fill and seal in
+        order (a sharded index too), which each segment's ``row_ids``
+        confirm."""
         cap = self.index_cfg.segment_capacity
         ids = np.asarray(ids, np.int64)
         U = np.empty((len(ids), self.cfg.vectors_per_row, self.cfg.k),
@@ -146,6 +185,11 @@ class System:
                 Us, Ms = sk.U, sk.moments
             else:
                 Us, Ms = self.index.active.U, self.index.active.moments
+            seg_ids = (self.index.sealed[s].row_ids if s < len(
+                self.index.sealed) else self.index.active.row_ids)
+            if not np.array_equal(seg_ids[ids[sel] % cap], ids[sel]):
+                raise RuntimeError(f"segment {s} does not hold ids "
+                                   f"{ids[sel][:4]}... at ingest positions")
             loc = jnp.asarray(ids[sel] % cap, jnp.int32)
             U[sel] = np.asarray(jnp.take(Us, loc, axis=0))
             M[sel] = np.asarray(jnp.take(Ms, loc, axis=0))

@@ -39,6 +39,13 @@ class Trace:
     programs: Dict[int, List[Op]]         # chip id -> program runs
     host: List[Op]                        # host events, any thread
 
+    @property
+    def device_events(self) -> int:
+        """Op and program events kept on every chip's plane: what the
+        profiler held of the device, which it caps."""
+        return (sum(map(len, self.devices.values()))
+                + sum(map(len, self.programs.values())))
+
     def window(self) -> Tuple[float, float]:
         """(start, end) of the benchmark's own window annotation."""
         spans = [(e.start, e.end) for e in self.host if e.name == WINDOW_EVENT]
@@ -146,6 +153,7 @@ class Summary:
     device_ops: List[list]
     idle_gaps: List[list]
     bounds_ns: Tuple[float, float]
+    events: int = 0                         # the trace's device events
 
     @property
     def mean_busy_s(self) -> float:
@@ -169,6 +177,7 @@ def summarize(trace: Trace, chips: List[int],
         device_ops=top_ops(trace, chips, lo, hi),
         idle_gaps=name_gaps(gaps, trace.host),
         bounds_ns=(lo, hi),
+        events=trace.device_events,
     )
 
 

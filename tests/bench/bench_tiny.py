@@ -19,3 +19,19 @@ def tiny_cell(bench: Benchmark, workload: str):
     tr = dict(cell.traffic, clients=8, query_pool=64, compare_max=64,
               batch_sizes=[8])
     return dataclasses.replace(cell, config=cfg, traffic=tr)
+
+
+def tiny_sharded_cell(bench: Benchmark, workload: str):
+    """A sharded cell cut to 4 shards x 2 segments of 64 rows, dim 16, and
+    8 clients: run it on four devices (on the CPU,
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``)."""
+    cell = bench.cell(workload)
+    cfg = copy.deepcopy(cell.config)
+    shards = cfg["index"]["shards"]
+    cfg.update(rows=shards * 2 * 64, dim=16)
+    cfg["sketch"].update(k=16, block_d=16)
+    cfg["index"].update(segment_rows=64, ingest_batch=64)
+    cfg["front_door"].update(max_batch=8)
+    tr = dict(cell.traffic, clients=8, query_pool=64, compare_max=64,
+              batch_sizes=[8])
+    return dataclasses.replace(cell, config=cfg, traffic=tr)

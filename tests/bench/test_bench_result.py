@@ -60,6 +60,57 @@ def test_client_orders_deal_one_permutation():
     assert [len(o) for o in orders] == [3, 3, 2, 2]
 
 
+def test_the_loop_completes_the_round_open_at_the_close():
+    """Four clients whose requests are served together, as a batcher that
+    closes a batch when every caller is in it serves them, and who come
+    back at staggered times: a close inside the stagger must not leave the
+    last batch short of a caller."""
+    import threading
+
+    import numpy as np
+
+    clients = 4
+    barrier = threading.Barrier(clients, timeout=1.0)
+
+    def send(row):
+        i = barrier.wait()
+        time.sleep(0.004 * i)
+        return np.zeros((1, 10)), np.zeros((1, 10), np.int64)
+
+    orders = loop.client_orders(np.random.default_rng(0), 16, clients)
+    pool = np.zeros((16, 2), np.float32)
+    for seconds in (0.05, 0.07, 0.09, 0.11):
+        requests, *_ = loop.run_closed_loop(send, pool, orders, seconds,
+                                            drain_s=5.0)
+        assert all(r.ok for r in requests), [r.error for r in requests]
+        counts = np.bincount([r.client for r in requests],
+                             minlength=clients)
+        assert len(set(counts.tolist())) == 1, counts
+
+
+def test_the_loop_calls_at_from_its_own_thread_while_clients_run_on():
+    import threading
+
+    import numpy as np
+
+    calls = []
+
+    def send(row):
+        time.sleep(0.002)
+        return np.zeros((1, 10)), np.zeros((1, 10), np.int64)
+
+    def at():
+        calls.append((threading.current_thread(), time.perf_counter()))
+
+    orders = loop.client_orders(np.random.default_rng(0), 8, 2)
+    requests, t_open, t_close, _ = loop.run_closed_loop(
+        send, np.zeros((8, 2), np.float32), orders, 0.3, at=(0.1, at))
+    assert len(calls) == 1 and calls[0][0] is threading.current_thread()
+    assert calls[0][1] - t_open == pytest.approx(0.1, abs=0.05)
+    assert max(r.t_send for r in requests) > calls[0][1] + 0.1
+    assert all(r.ok for r in requests)
+
+
 @pytest.fixture(scope="module")
 def lines():
     bench = Benchmark()

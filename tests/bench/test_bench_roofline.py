@@ -62,3 +62,27 @@ def test_mfu_reader_bounds_the_kernel_share_from_below():
     w = _window(kernel_s=0.01, batches=[(64, 1.0)])
     mfu = bench.metric_reader("knn_mfu")(w)
     assert 0 < mfu < bench.metric_reader("pairwise_lp_roofline")(w)
+
+
+def test_four_chip_readers_divide_like_for_like():
+    """Kernel time summed over four chips against one chip's peak: four
+    chips that each spend a quarter of one chip's kernel time on a quarter
+    of its work read that chip's share.  ``knn_mfu`` divides by the window
+    times the four chips."""
+    bench = Benchmark()
+    peak = roofline.peaks("TPU v5 lite")
+    one = _window(kernel_s=0.02, batches=[(64, 1.0)], live=4_000_000)
+    chips = [0, 1, 2, 3]
+    s = Summary(window_s=10.0, chips=chips, busy_s=dict.fromkeys(chips, 1.0),
+                ops=dict.fromkeys(chips, 100),
+                programs=dict.fromkeys(chips, 10),
+                kernel_s={"pairwise_lp": 0.02}, device_ops=[], idle_gaps=[],
+                bounds_ns=(0, 1e10))
+    four = Window(trace=s, traced_batches=[(64, 1.0)], live_rows=4_000_000,
+                  packed_width=768, peak=peak, chips=4)
+    for name in ("pairwise_lp_roofline", "knn_mfu"):
+        assert bench.metric_reader(name)(four) is not None
+    assert bench.metric_reader("pairwise_lp_roofline")(four) == \
+        pytest.approx(bench.metric_reader("pairwise_lp_roofline")(one))
+    assert bench.metric_reader("knn_mfu")(four) == \
+        pytest.approx(bench.metric_reader("knn_mfu")(one) / 4)

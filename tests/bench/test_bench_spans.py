@@ -100,3 +100,41 @@ def test_a_tiny_traced_run_reports_the_span_metrics(tmp_path):
         "stage1_collect_ms"]
     assert parts <= m["index_flush_ms"]
     assert m["queue_wait_ms"] >= 0.0
+
+
+def test_trace_window_s_cuts_the_traced_part_while_the_loop_runs_on(
+        tmp_path, monkeypatch):
+    from bench import loop
+
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    # another run's trace under the same root must survive this one
+    other = tmp_path / ".bench_trace" / "run-other"
+    other.mkdir(parents=True)
+    (other / "keep").write_text("x")
+    seen = {}
+    real = loop.run_closed_loop
+
+    def recorded(*a, **kw):
+        out = seen["out"] = real(*a, **kw)
+        return out
+
+    monkeypatch.setattr(loop, "run_closed_loop", recorded)
+    bench = Benchmark(tmp_path)
+    workload = "gist1m-p4.knn-plain"
+    cell = tiny_cell(bench, workload)
+    cell.config["trace_window_s"] = 0.3
+    r = run_cell(bench, workload, seed=2**31 + 29, seconds=1.2, trace=True,
+                 t_process=time.perf_counter(), require_chip=False, cell=cell,
+                 log=lambda m: None)
+    assert r["correct"] is True
+    assert 0.3 <= r["device"]["window_s"] < 0.55
+    requests, t_open, t_close, _ = seen["out"]
+    assert t_close - t_open == pytest.approx(1.2)
+    assert max(q.t_send for q in requests) > t_open + 0.9
+    assert {"batch_rows_mean", "index_flush_ms"} <= set(r["metrics"])
+    assert r["device"]["trace_events"] >= 0
+    assert (other / "keep").read_text() == "x"
+    assert sorted(p.name for p in (tmp_path / ".bench_trace").iterdir()) == [
+        "run-other"]
